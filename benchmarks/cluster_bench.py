@@ -495,6 +495,9 @@ def main(fast: bool = False, replicas_list=(1, 2, 4),
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--replicas", default="1,2,4",

@@ -374,4 +374,7 @@ def _cli() -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     _cli()

@@ -382,6 +382,9 @@ def main(fast: bool = False,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--backends", default="xla,pallas_block_scan",

@@ -106,5 +106,8 @@ def main(scale: str = "small", out: str = "results/table1.json"):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import sys as _s
     main(scale=_s.argv[1] if len(_s.argv) > 1 else "small")

@@ -185,8 +185,9 @@ class ReplicaSet:
 
         from repro.index.live.segments import BaseSegment, MANIFEST_NAME
 
-        from .proc import ProcessReplica
+        from .proc.replica import ProcessReplica, refuse_on_tpu
 
+        refuse_on_tpu()
         self._proc_root = Path(self.cfg.proc_storage_dir
                                or tempfile.mkdtemp(prefix="repro-proc-cell-"))
         base_dir = self._proc_root / "base"

@@ -40,13 +40,29 @@ from .messages import (REQUEST_BYTES, decode_response, encode_request,
                        encode_request_block, response_bytes)
 from .ring import RingClosed, ShmRing
 
-__all__ = ["ProcessReplica"]
+__all__ = ["ProcessReplica", "refuse_on_tpu"]
 
 _READY_TIMEOUT_S = 600.0      # child imports jax + rebuilds the system
 _REPLY_TIMEOUT_S = 600.0      # warmup compiles on the worker
 _DEAD_DEPTH = 1 << 30         # router poison for an exhausted replica
 _N_PINGS = 4                  # clock-handshake samples per (re)spawn
 _TRACE_TAIL = 8192            # merged worker trace entries kept parent-side
+
+
+def refuse_on_tpu() -> None:
+    """Refuse to start worker processes while a TPU is the default
+    backend.  A chip belongs to one process: this parent already holds
+    it (it built and trained the system on JAX), so every worker's
+    engine would fail or hang reaching the device.  Raises before any
+    worker is spawned; on a TPU host serve with the thread backend."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "the process replica backend cannot run on a TPU host: the "
+            "parent process already holds the chip, and a TPU serves one "
+            "process at a time, so worker engines would fail or hang. "
+            "Use ClusterConfig(backend='thread') on the chip.")
 
 
 class ProcessReplica:
@@ -113,6 +129,7 @@ class ProcessReplica:
     def start(self) -> "ProcessReplica":
         if self._proc is not None:
             raise RuntimeError(f"process replica {self.idx} already started")
+        refuse_on_tpu()
         self._spawn()
         self._collector = threading.Thread(
             target=self._collect_loop, name=f"proc-replica-{self.idx}",

@@ -25,10 +25,10 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.index.corpus import A, U, B, T, N_FIELDS
 from repro.index.builder import MAX_QUERY_TERMS
-from repro.kernels.common import reduce_and, reduce_or
 
 __all__ = ["RuleSet", "default_rule_library", "scan_block", "block_cost"]
 
@@ -130,12 +130,12 @@ def scan_block(
     """
     mask = (allowed & term_present[:, None]).astype(jnp.uint32)          # (T, F)
     planes = occ_block * mask[..., None]                                 # (T, F, W)
-    tf_or = reduce_or(planes, (1,))                                      # (T, W)
+    tf_or = lax.reduce_or(planes, (1,))                                  # (T, W)
 
     req = (required & term_present).astype(jnp.uint32)[:, None]          # (T, 1)
     # Non-required slots contribute all-ones to the conjunction.
     conj_in = tf_or | (jnp.uint32(0xFFFFFFFF) * (1 - req))
-    match = reduce_and(conj_in, (0,))                                    # (W,)
+    match = lax.reduce_and(conj_in, (0,))                                # (W,)
     any_req = jnp.any(required & term_present)
     match = jnp.where(any_req, match, jnp.uint32(0))
 

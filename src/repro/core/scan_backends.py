@@ -329,8 +329,9 @@ class PallasBlockScanBackend(ScanBackend):
     :data:`DEFAULT_CHUNK_BLOCKS` when quotas are traced.  The final
     state is C-invariant either way (pinned by
     ``tests/test_scan_backends.py::test_chunk_size_invariance``).
-    ``interpret=None`` follows ``kernels.common.INTERPRET`` (interpret
-    mode on CPU, compiled on TPU).
+    ``interpret=None`` is decided per kernel call by
+    ``kernels.common.resolve_interpret`` (interpret mode off the TPU,
+    compiled on it).
     """
 
     name = "pallas_block_scan"

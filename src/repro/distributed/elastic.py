@@ -27,7 +27,8 @@ def plan_mesh_shape(n_devices: int, prefer_model: int = 16):
 
 def plan_mesh(n_devices: int, prefer_model: int = 16):
     """Pick a (data, model) mesh for an arbitrary device count."""
-    return jax.make_mesh(plan_mesh_shape(n_devices, prefer_model), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    return make_mesh(plan_mesh_shape(n_devices, prefer_model), ("data", "model"))
 
 
 def validate_specs(tree: Any, specs: Any, mesh) -> List[str]:

@@ -10,7 +10,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["sharded_lookup", "sharded_lookup_rs", "sharded_bag_sum"]
@@ -34,11 +33,11 @@ def sharded_lookup(table: jnp.ndarray, idx: jnp.ndarray, mesh,
 
     ispec = P(data_axes, None) if data_axes else P()
     ospec = P(data_axes, None, None) if data_axes else P()
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(model_axis, None), ispec),
         out_specs=ospec,
-        check_rep=False,
+        check_vma=False,
     )(table, idx)
 
 
@@ -57,11 +56,11 @@ def sharded_bag_sum(table: jnp.ndarray, idx: jnp.ndarray, mesh,
         rows = rows * ok[..., None].astype(rows.dtype)
         return lax.psum(rows.sum(1), model_axis)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(model_axis, None), P(data_axes, None)),
         out_specs=P(data_axes, None),
-        check_rep=False,
+        check_vma=False,
     )(table, idx)
 
 
@@ -90,9 +89,9 @@ def sharded_lookup_rs(table: jnp.ndarray, idx: jnp.ndarray, mesh,
         rows = rows * ok[..., None].astype(rows.dtype)           # (B_loc, F, E)
         return lax.psum_scatter(rows, model_axis, scatter_dimension=0, tiled=True)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(model_axis, None), P(data_axes, None)),
         out_specs=P(data_axes + (model_axis,), None, None),
-        check_rep=False,
+        check_vma=False,
     )(table, idx)

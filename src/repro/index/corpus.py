@@ -18,8 +18,11 @@ that feeds the JAX query-evaluation runtime.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Dict, List
+import multiprocessing as mp
+import os
+from typing import List
 
 import numpy as np
 
@@ -64,35 +67,29 @@ def _zipf_probs(vocab: int, a: float) -> np.ndarray:
     return p / p.sum()
 
 
-def generate_corpus(config: CorpusConfig = CorpusConfig()) -> Corpus:
-    rng = np.random.default_rng(config.seed)
+DOC_CHUNK = 1 << 16   # documents drawn from one random stream
+
+
+def _generate_docs(config: CorpusConfig, rng: np.random.Generator,
+                   topic_terms: np.ndarray, static_rank: np.ndarray,
+                   doc_topic: np.ndarray) -> List[List[np.ndarray]]:
+    """Per-field term arrays for a run of documents (one per entry of
+    ``static_rank``/``doc_topic``), drawn from ``rng`` in doc order."""
     vocab = config.vocab_size
-
-    probs = _zipf_probs(vocab, config.zipf_a)
-
-    # Latent topics: each topic owns a pocket of moderately rare terms.
-    topic_vocab = max(8, vocab // config.n_topics)
-    topic_terms = np.zeros((config.n_topics, topic_vocab), dtype=np.int32)
-    # Topic terms drawn from the rarer half of the vocabulary so topical
-    # queries are CAT1-like (rare multi-term).
-    rare_pool = np.arange(vocab // 4, vocab, dtype=np.int32)
-    for k in range(config.n_topics):
-        topic_terms[k] = rng.choice(rare_pool, size=topic_vocab, replace=False)
-
-    # Static rank: exponential-ish decay, already sorted descending.
-    static_rank = np.sort(rng.exponential(scale=1.0, size=config.n_docs))[::-1]
-    static_rank = (static_rank / static_rank.max()).astype(np.float32)
-
-    doc_topic = rng.integers(0, config.n_topics, size=config.n_docs).astype(np.int32)
+    topic_vocab = topic_terms.shape[1]
+    # rng.choice(vocab, p=probs) without rebuilding the CDF per call:
+    # the same cumsum, normalisation and uniform draws, so the same ids.
+    cdf = _zipf_probs(vocab, config.zipf_a).cumsum()
+    cdf /= cdf[-1]
 
     field_terms: List[List[np.ndarray]] = [[] for _ in range(N_FIELDS)]
-    for d in range(config.n_docs):
+    for d in range(len(static_rank)):
         topic = doc_topic[d]
         n_body = max(4, rng.poisson(config.body_terms))
         # Body = Zipf background + topical pocket.
         n_topical = max(2, n_body // 4)
         body = np.union1d(
-            rng.choice(vocab, size=n_body - n_topical, p=probs),
+            cdf.searchsorted(rng.random(n_body - n_topical), side="right"),
             rng.choice(topic_terms[topic], size=n_topical),
         ).astype(np.int32)
 
@@ -121,6 +118,58 @@ def generate_corpus(config: CorpusConfig = CorpusConfig()) -> Corpus:
         field_terms[U].append(url)
         field_terms[B].append(np.unique(body))
         field_terms[T].append(np.unique(title))
+    return field_terms
+
+
+def _generate_chunk(args) -> List[tuple]:
+    """Worker body: chunk ``i`` of the corpus from its own stream, each
+    field packed as (flat terms, per-doc lengths) for a cheap return."""
+    config, i, topic_terms, static_rank, doc_topic = args
+    rng = np.random.default_rng([config.seed, i])
+    fields = _generate_docs(config, rng, topic_terms, static_rank, doc_topic)
+    return [(np.concatenate(lists), np.fromiter(map(len, lists), np.int64, len(lists)))
+            for lists in fields]
+
+
+def generate_corpus(config: CorpusConfig = CorpusConfig()) -> Corpus:
+    """Documents come in chunks of :data:`DOC_CHUNK`.  The first is drawn
+    from the corpus stream itself; chunk ``i`` >= 1 from a stream seeded
+    by ``(seed, i)``, so later chunks are generated in parallel worker
+    processes (numpy only, they never touch an accelerator) and the
+    corpus is the same whatever the number of workers."""
+    rng = np.random.default_rng(config.seed)
+    vocab = config.vocab_size
+
+    # Latent topics: each topic owns a pocket of moderately rare terms.
+    topic_vocab = max(8, vocab // config.n_topics)
+    topic_terms = np.zeros((config.n_topics, topic_vocab), dtype=np.int32)
+    # Topic terms drawn from the rarer half of the vocabulary so topical
+    # queries are CAT1-like (rare multi-term).
+    rare_pool = np.arange(vocab // 4, vocab, dtype=np.int32)
+    for k in range(config.n_topics):
+        topic_terms[k] = rng.choice(rare_pool, size=topic_vocab, replace=False)
+
+    # Static rank: exponential-ish decay, already sorted descending.
+    static_rank = np.sort(rng.exponential(scale=1.0, size=config.n_docs))[::-1]
+    static_rank = (static_rank / static_rank.max()).astype(np.float32)
+
+    doc_topic = rng.integers(0, config.n_topics, size=config.n_docs).astype(np.int32)
+
+    jobs = [(config, i, topic_terms, static_rank[lo:lo + DOC_CHUNK],
+             doc_topic[lo:lo + DOC_CHUNK])
+            for i, lo in enumerate(range(DOC_CHUNK, config.n_docs, DOC_CHUNK), 1)]
+    n_workers = min(len(jobs), len(os.sched_getaffinity(0)))
+    with (mp.get_context("spawn").Pool(n_workers) if jobs
+          else contextlib.nullcontext()) as pool:
+        # Workers start on the later chunks while this process draws the
+        # first one.
+        pending = pool.map_async(_generate_chunk, jobs) if jobs else None
+        head = slice(0, DOC_CHUNK)
+        field_terms = _generate_docs(config, rng, topic_terms,
+                                     static_rank[head], doc_topic[head])
+        for packed in (pending.get() if jobs else ()):
+            for f, (flat, lens) in enumerate(packed):
+                field_terms[f].extend(np.split(flat, np.cumsum(lens)[:-1]))
 
     return Corpus(
         config=config,

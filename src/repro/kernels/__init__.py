@@ -1,1 +1,1 @@
-from .common import INTERPRET
+from .common import resolve_interpret

@@ -8,9 +8,9 @@ exactly the paper's ``u`` (bytes of index read).
 
 Layout:
     occ      (n_blocks, T*F, W) uint32    one W-word plane per (term, field)
-    masks    (8, T*F)           uint32    row 0: allowed∧present, row 1:
-                                          required∧present per TERM group
-                                          (padded to 8 rows for tiling)
+    masks    (2, T*F)           int32     SMEM; row 0: allowed∧present per
+                                          plane, row 1: required∧present
+                                          per term (first T columns)
 Outputs per index block:
     match    (n_blocks, W)      uint32    docs satisfying ∧_t ∨_f occ
     counts   (n_blocks, 8)      int32     col 0: v increment (term matches),
@@ -30,36 +30,58 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import INTERPRET, cdiv, reduce_and, reduce_or, tpu_compiler_params
+from repro.kernels.common import cdiv, resolve_interpret
 
-__all__ = ["block_scan_pallas"]
+__all__ = ["block_scan_pallas", "rule_match", "count_lanes"]
 
 
-def _kernel(occ_ref, masks_ref, match_ref, counts_ref, *, t: int, f: int):
-    occ = occ_ref[...]                     # (BB, T*F, W) uint32
-    masks = masks_ref[...]                 # (8, T*F)    uint32
-    bb, tf, w = occ.shape
+def _popcount_rows(x: jnp.ndarray) -> jnp.ndarray:
+    """(R, W) uint32 → (R, 1) int32 set-bit count per row."""
+    return jnp.sum(jax.lax.population_count(x).astype(jnp.int32), axis=1,
+                   keepdims=True)
 
-    allowed = masks[0]                     # (T*F,) 0/1  (already ∧ present)
-    required = masks[1]                    # (T*F,) 0/1  (per-term, replicated over F)
 
-    planes = occ * allowed[None, :, None]                 # (BB, T*F, W)
-    grouped = planes.reshape(bb, t, f, w)
-    tf_or = reduce_or(grouped, (2,))         # (BB, T, W)
+def rule_match(plane, plane_active, term_required, *, t: int, f: int):
+    """One match rule over a tile of R blocks, in elementwise ops only:
+    the TPU has no vector reductions over unsigned integers, so the
+    per-term OR over fields and the AND over required terms are
+    unrolled over the static T and F.
 
-    req = required.reshape(t, f)[:, 0]                    # (T,)
-    full = jnp.uint32(0xFFFFFFFF)
-    conj_in = tf_or | (full * (jnp.uint32(1) - req))[None, :, None]
-    match = reduce_and(conj_in, (1,))        # (BB, W)
-    any_req = (jnp.sum(req) > 0).astype(jnp.uint32)
-    match = match * any_req
+    ``plane(p)`` gives plane p's (R, W) uint32 rows; ``plane_active(p)``
+    and ``term_required(j)`` give scalar flags (allowed∧present,
+    required∧present).  Returns (match (R, W) uint32, v_inc (R, 1) int32,
+    n_match (R, 1) int32).
+    """
+    conj, any_req, v_inc = None, False, 0
+    for j in range(t):
+        tf_or = None
+        for k in range(f):
+            row = plane(j * f + k)
+            row = jnp.where(plane_active(j * f + k), row, jnp.zeros_like(row))
+            tf_or = row if tf_or is None else tf_or | row
+        req = term_required(j)
+        term = jnp.where(req, tf_or, jnp.full_like(tf_or, 0xFFFFFFFF))
+        conj = term if conj is None else conj & term
+        any_req = any_req | req
+        v_inc = v_inc + _popcount_rows(tf_or)
+    match = jnp.where(any_req, conj, jnp.zeros_like(conj))
+    return match, v_inc, _popcount_rows(match)
 
-    v_inc = jnp.sum(jax.lax.population_count(tf_or).astype(jnp.int32), axis=(1, 2))
-    n_match = jnp.sum(jax.lax.population_count(match).astype(jnp.int32), axis=1)
 
+def count_lanes(shape, v_inc, n_match):
+    """Counts tile: lane 0 v_inc, lane 1 n_match, zeros elsewhere."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return jnp.where(lanes == 0, v_inc, jnp.where(lanes == 1, n_match, 0))
+
+
+def _kernel(masks_ref, occ_ref, match_ref, counts_ref, *, t: int, f: int):
+    # masks (SMEM): row 0 allowed∧present per plane, row 1
+    # required∧present per term; planes are read with static indices.
+    match, v_inc, n_match = rule_match(
+        lambda p: occ_ref[:, p, :], lambda p: masks_ref[0, p] != 0,
+        lambda j: masks_ref[1, j] != 0, t=t, f=f)
     match_ref[...] = match
-    zeros = jnp.zeros((bb, 6), jnp.int32)
-    counts_ref[...] = jnp.concatenate([v_inc[:, None], n_match[:, None], zeros], axis=1)
+    counts_ref[...] = count_lanes(counts_ref.shape, v_inc, n_match)
 
 
 def block_scan_pallas(
@@ -76,18 +98,16 @@ def block_scan_pallas(
     Returns (match_words (n_blocks, W) uint32, v_inc (n_blocks,) int32,
     n_match (n_blocks,) int32).
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     nb, t, f, w = occ.shape
     occ2 = occ.reshape(nb, t * f, w)
     pad = cdiv(nb, block_bb) * block_bb - nb
     if pad:
         occ2 = jnp.pad(occ2, ((0, pad), (0, 0), (0, 0)))
 
-    amask = (allowed & term_present[:, None]).astype(jnp.uint32).reshape(t * f)
-    rmask = jnp.broadcast_to(
-        (required & term_present).astype(jnp.uint32)[:, None], (t, f)
-    ).reshape(t * f)
-    masks = jnp.zeros((8, t * f), jnp.uint32).at[0].set(amask).at[1].set(rmask)
+    amask = (allowed & term_present[:, None]).astype(jnp.int32).reshape(t * f)
+    rmask = (required & term_present).astype(jnp.int32)
+    masks = jnp.zeros((2, t * f), jnp.int32).at[0].set(amask).at[1, :t].set(rmask)
 
     grid = (cdiv(nb, block_bb),)
     kernel = functools.partial(_kernel, t=t, f=f)
@@ -95,8 +115,8 @@ def block_scan_pallas(
         kernel,
         grid=grid,
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((block_bb, t * f, w), lambda b: (b, 0, 0)),
-            pl.BlockSpec((8, t * f), lambda b: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_bb, w), lambda b: (b, 0)),
@@ -106,10 +126,10 @@ def block_scan_pallas(
             jax.ShapeDtypeStruct((grid[0] * block_bb, w), jnp.uint32),
             jax.ShapeDtypeStruct((grid[0] * block_bb, 8), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary",),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
         ),
         interpret=interpret,
         name="block_scan",
-    )(occ2, masks)
+    )(masks, occ2)
     return match[:nb], counts[:nb, 0], counts[:nb, 1]

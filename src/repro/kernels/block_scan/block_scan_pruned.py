@@ -5,37 +5,40 @@ The baseline kernel (and the XLA executor path) DMAs the full
 (T·F, W) occupancy tile per block and masks in VMEM — for a shallow
 rule like mr_B (2 active planes of 16) that wastes 8× HBM bandwidth,
 and the paper's whole point is that shallow rules are CHEAP.  Here the
-active-plane list is a scalar-prefetch operand driving the occupancy
-BlockSpec index_map, so the DMA engine fetches exactly
-``n_active × W`` words per block: bytes streamed = u (the paper's cost
-accumulator), not T·F·W.
+occupancy stays in HBM and the kernel issues one DMA per ACTIVE plane,
+driven by the plane list in a scalar-prefetched meta: bytes streamed =
+``n_active × W`` words per block = u (the paper's cost accumulator),
+not T·F·W.
+
+Why manual DMAs: a single plane is a (1, W) row of the (T·F, W) tile,
+and a BlockSpec of that shape breaks the TPU's (8, 128) tiling rule
+(second-minor block dim 1 on an array dim of T·F), squeezed or not.
+Fetching the whole tile and selecting rows in VMEM would stream T·F·W
+words whatever the rule.  ``make_async_copy`` of one row is legal, so
+each active plane is copied into its own row of a (T·F, W) VMEM buffer
+and inactive rows are masked out by a per-plane flag.
 
 Two entry points:
 
-``block_scan_pruned_pallas``
-    The original static-rule kernel: the rule masks are host (numpy)
-    values, the active-plane list is computed at trace time, and the
-    grid covers exactly the active planes.  Grid: (n_blocks, n_active).
-
 ``block_scan_pruned_chunk``
-    The serving/rollout variant behind the ``pallas_block_scan`` scan
+    The serving/rollout kernel behind the ``pallas_block_scan`` scan
     backend (core/scan_backends.py): rule masks are TRACED (chosen by
-    the policy at runtime), so the plane-step count is the static
-    worst case P = T·F and a per-step validity flag in the prefetched
-    meta masks the padding steps.  Padding steps map to the last
-    active plane, so the Pallas pipeline's revisiting-block elision
-    skips their DMA — bytes streamed stays ∝ n_active, not P.  The
-    kernel processes a static chunk of C consecutive blocks per launch
-    for a whole query batch: grid (B, C, P), block start per lane read
-    from the meta.  Inactive lanes / out-of-range blocks are clamped
+    the policy at runtime).  Each launch evaluates a static chunk of C
+    consecutive blocks for a whole query batch: grid (B, C), block
+    start per lane read from the meta.  Out-of-range blocks are clamped
     to block n_blocks-1 and masked by the caller.
+
+``block_scan_pruned_pallas``
+    A static (host numpy) rule over every block of one occupancy
+    tensor — the same kernel, with the index's blocks split into
+    chunk-sized lanes that share one occupancy.
 
 Semantics are pinned against ``block_scan_reference``
 (kernels/block_scan/ref.py → core.match_rules.scan_block) for every
-edge, including rules with ZERO active planes (the occupancy read is
-fully masked: match = 0, v_inc = 0) and rules with no required terms
-(match = 0 but v_inc still counts term hits among the planes the rule
-paid to inspect — u is charged, so v is too).
+edge, including rules with ZERO active planes (nothing is read:
+match = 0, v_inc = 0) and rules with no required terms (match = 0 but
+v_inc still counts term hits among the planes the rule paid to
+inspect — u is charged, so v is too).
 """
 from __future__ import annotations
 
@@ -44,115 +47,21 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import INTERPRET, cdiv, reduce_and, tpu_compiler_params
+from repro.kernels.block_scan.block_scan import count_lanes, rule_match
+from repro.kernels.common import cdiv, resolve_interpret
 
 __all__ = [
     "block_scan_pruned_pallas", "block_scan_pruned_chunk", "build_rule_meta",
     "META_ROWS", "META_BP_COL",
 ]
 
-
-def _kernel(meta_ref, occ_ref, match_ref, counts_ref, tf_scr,
-            *, t: int, n_active: int):
-    pi = pl.program_id(1)
-
-    @pl.when(pi == 0)
-    def _init():
-        tf_scr[...] = jnp.zeros_like(tf_scr)
-
-    # meta row 0: plane ids (t*F+f); row 1: term id per active plane;
-    # row 2: step-valid flag (0 for the padding plane when the rule has
-    # no active planes); row 3: required-mask per term (length-t prefix).
-    term = meta_ref[1, pi]
-    valid = meta_ref[2, pi].astype(jnp.uint32)
-    plane = occ_ref[0]                                  # (1, W) active plane
-    # OR this plane into its term's running bitmap (masked when padding).
-    row = tf_scr[term]
-    tf_scr[term] = row | (plane[0] * valid)
-
-    @pl.when(pi == n_active - 1)
-    def _finalize():
-        tf = tf_scr[...]                                # (t, W)
-        full = jnp.uint32(0xFFFFFFFF)
-        req = meta_ref[3, :t].astype(jnp.uint32)        # (t,) 0/1
-        conj = tf | (full * (jnp.uint32(1) - req))[:, None]
-        match = reduce_and(conj, (0,))
-        any_req = (jnp.sum(req) > 0).astype(jnp.uint32)
-        match = match * any_req
-        match_ref[0] = match
-        v_inc = jnp.sum(jax.lax.population_count(tf).astype(jnp.int32))
-        n_match = jnp.sum(jax.lax.population_count(match).astype(jnp.int32))
-        counts_ref[0, 0] = v_inc
-        counts_ref[0, 1] = n_match
-
-
-def block_scan_pruned_pallas(
-    occ: jnp.ndarray,            # (n_blocks, T, F, W) uint32
-    allowed: np.ndarray,         # (T, F) bool — STATIC (host) rule mask
-    required: np.ndarray,        # (T,) bool — static
-    term_present: np.ndarray,    # (T,) bool — static
-    *,
-    interpret: bool | None = None,
-):
-    """Returns (match (nb, W) u32, v_inc (nb,) i32, n_match (nb,) i32).
-    The rule is static: only its active planes are ever read from HBM."""
-    interpret = INTERPRET if interpret is None else interpret
-    nb, t, f, w = occ.shape
-    amask = np.asarray(allowed) & np.asarray(term_present)[:, None]
-    planes = np.argwhere(amask.reshape(-1)).ravel()       # active plane ids
-    n_steps = max(len(planes), 1)
-    # A rule with zero active planes still launches one (masked) step so
-    # the grid is non-empty; the valid flag keeps its occupancy read out
-    # of tf (v_inc = 0, match = 0 — pinned against block_scan_reference).
-    step_valid = np.ones(n_steps, np.int32)
-    if len(planes) == 0:
-        planes = np.array([0])
-        step_valid[0] = 0
-
-    meta = np.zeros((4, max(t * f, t)), np.int32)
-    meta[0, :n_steps] = planes
-    meta[1, :n_steps] = planes // f                       # term of each plane
-    meta[2, :n_steps] = step_valid
-    meta[3, :t] = (np.asarray(required) & np.asarray(term_present)).astype(np.int32)
-
-    occ2 = occ.reshape(nb, t * f, w)
-
-    kernel = functools.partial(_kernel, t=t, n_active=n_steps)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb, n_steps),
-        in_specs=[
-            # stream exactly the active plane for this grid step
-            pl.BlockSpec((1, 1, w), lambda b, p, m: (b, m[0, p], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, w), lambda b, p, m: (b, 0)),
-            pl.BlockSpec((1, 8), lambda b, p, m: (b, 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((t, w), jnp.uint32)],
-    )
-    match, counts = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, w), jnp.uint32),
-            jax.ShapeDtypeStruct((nb, 8), jnp.int32),
-        ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
-        interpret=interpret,
-        name="block_scan_pruned",
-    )(jnp.asarray(meta), occ2)
-    return match, counts[:, 0], counts[:, 1]
-
-
-# --------------------------------------------------------- chunked variant
-META_ROWS = 4          # plane id / term id / step valid / required per term
-META_BP_COL = -1       # meta[:, 0, -1] holds the lane's block start
+META_ROWS = 3          # active plane list / per-plane flag / required per term
+META_BP_COL = -1       # meta[:, 0, -1]: block start; meta[:, 1, -1]: n_active
+COUNT_LANES = 128      # counts output row: lane 0 v_inc, lane 1 n_match
 
 
 def build_rule_meta(
@@ -163,60 +72,95 @@ def build_rule_meta(
 ) -> jnp.ndarray:
     """Scalar-prefetch meta for ``block_scan_pruned_chunk`` (traced).
 
-    Active planes (allowed ∧ present, flattened t*F+f) are listed first
-    via a stable argsort; the P - n_active padding steps repeat the LAST
-    active plane with valid = 0, so the pipeline re-uses the resident
-    VMEM buffer instead of issuing fresh DMAs for them.
+    Row 0 lists the active planes (allowed ∧ present, flattened t·F+f)
+    first, via a stable argsort; its last column is the lane's block
+    start.  Row 1 flags each plane id active (0/1); its last column is
+    the active-plane count.  Row 2 flags each term required ∧ present.
     """
     b, t, f = allowed.shape
     p_steps = t * f
     act = (allowed & term_present[:, :, None]).reshape(b, p_steps)
     order = jnp.argsort(~act, axis=1, stable=True).astype(jnp.int32)
-    n_active = jnp.sum(act, axis=1).astype(jnp.int32)
-    last = jnp.take_along_axis(order, jnp.maximum(n_active - 1, 0)[:, None], axis=1)
-    steps = jnp.arange(p_steps, dtype=jnp.int32)[None, :]
-    valid = (steps < n_active[:, None]).astype(jnp.int32)
-    plane_ids = jnp.where(valid == 1, order, last)
+    n_active = jnp.sum(act, axis=1, dtype=jnp.int32)
 
-    ncols = max(p_steps + 1, t + 1, 8)
+    ncols = max(p_steps + 1, 8)
     meta = jnp.zeros((b, META_ROWS, ncols), jnp.int32)
-    meta = meta.at[:, 0, :p_steps].set(plane_ids)
-    meta = meta.at[:, 0, ncols - 1].set(block_start.astype(jnp.int32))
-    meta = meta.at[:, 1, :p_steps].set(plane_ids // f)
-    meta = meta.at[:, 2, :p_steps].set(valid)
-    meta = meta.at[:, 3, :t].set((required & term_present).astype(jnp.int32))
+    meta = meta.at[:, 0, :p_steps].set(order)
+    meta = meta.at[:, 0, META_BP_COL].set(block_start.astype(jnp.int32))
+    meta = meta.at[:, 1, :p_steps].set(act.astype(jnp.int32))
+    meta = meta.at[:, 1, META_BP_COL].set(n_active)
+    meta = meta.at[:, 2, :t].set((required & term_present).astype(jnp.int32))
     return meta
 
 
-def _chunk_kernel(meta_ref, occ_ref, match_ref, counts_ref, tf_scr,
-                  *, t: int, p_steps: int):
+def _chunk_kernel(meta_ref, occ_hbm, match_ref, counts_ref, buf, sems,
+                  *, t: int, n_fields: int, n_blocks: int, shared_occ: bool):
     bi = pl.program_id(0)
-    pi = pl.program_id(2)
+    c = pl.program_id(1)
+    chunk, w = match_ref.shape
+    last = meta_ref.shape[-1] - 1
+    blk = jnp.minimum(meta_ref[bi, 0, last] + c, n_blocks - 1)
+    n_active = meta_ref[bi, 1, last]
+    lane = 0 if shared_occ else bi
 
-    @pl.when(pi == 0)
-    def _init():
-        tf_scr[...] = jnp.zeros_like(tf_scr)
+    def copy(k):
+        plane = meta_ref[bi, 0, k]
+        return pltpu.make_async_copy(
+            occ_hbm.at[lane, blk, pl.ds(plane, 1)],
+            buf.at[pl.ds(plane, 1)], sems.at[k])
 
-    term = meta_ref[bi, 1, pi]
-    valid = meta_ref[bi, 2, pi].astype(jnp.uint32)
-    plane = occ_ref[0, 0]                               # (1, W) current plane
-    row = tf_scr[term]
-    tf_scr[term] = row | (plane[0] * valid)
+    # Issue every active plane's DMA, then wait for all of them: only
+    # n_active rows of the block ever leave HBM.
+    lax.fori_loop(0, n_active, lambda k, _: copy(k).start(), None)
+    lax.fori_loop(0, n_active, lambda k, _: copy(k).wait(), None)
 
-    @pl.when(pi == p_steps - 1)
-    def _finalize():
-        tf = tf_scr[...]                                # (t, W)
-        full = jnp.uint32(0xFFFFFFFF)
-        req = meta_ref[bi, 3, :t].astype(jnp.uint32)
-        conj = tf | (full * (jnp.uint32(1) - req))[:, None]
-        match = reduce_and(conj, (0,))
-        any_req = (jnp.sum(req) > 0).astype(jnp.uint32)
-        match = match * any_req
-        match_ref[0, 0] = match
-        v_inc = jnp.sum(jax.lax.population_count(tf).astype(jnp.int32))
-        n_match = jnp.sum(jax.lax.population_count(match).astype(jnp.int32))
-        counts_ref[0, 0, 0] = v_inc
-        counts_ref[0, 0, 1] = n_match
+    # Rows of inactive planes hold stale data: their flags mask them out.
+    match, v_inc, n_match = rule_match(
+        lambda p: buf[p:p + 1, :], lambda p: meta_ref[bi, 1, p] != 0,
+        lambda j: meta_ref[bi, 2, j] != 0, t=t, f=n_fields)
+
+    # The (C, ·) output tiles stay resident across the chunk axis; row c
+    # is this block's.  Full-tile selects keep every store tile-aligned.
+    rows = lax.broadcasted_iota(jnp.int32, (chunk, w), 0) == c
+    match_ref[...] = jnp.where(rows, jnp.broadcast_to(match, (chunk, w)),
+                               match_ref[...])
+    rows = lax.broadcasted_iota(jnp.int32, (chunk, COUNT_LANES), 0) == c
+    counts = count_lanes((chunk, COUNT_LANES), v_inc, n_match)
+    counts_ref[...] = jnp.where(rows, counts, counts_ref[...])
+
+
+def _chunk_call(occ, meta, *, chunk: int, n_terms: int, shared_occ: bool,
+                interpret: bool | None):
+    interpret = resolve_interpret(interpret)
+    b = meta.shape[0]
+    _, nb, tf, w = occ.shape
+    kernel = functools.partial(_chunk_kernel, t=n_terms, n_fields=tf // n_terms,
+                               n_blocks=nb, shared_occ=shared_occ)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, chunk),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[
+            pl.BlockSpec((None, chunk, w), lambda bi, c, m: (bi, 0, 0)),
+            pl.BlockSpec((None, chunk, COUNT_LANES), lambda bi, c, m: (bi, 0, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((tf, w), jnp.uint32),
+                        pltpu.SemaphoreType.DMA((tf,))],
+    )
+    match, counts = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, chunk, w), jnp.uint32),
+            jax.ShapeDtypeStruct((b, chunk, COUNT_LANES), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+        name="block_scan_pruned_chunk",
+    )(meta, occ)
+    return match, counts[..., 0], counts[..., 1]
 
 
 def block_scan_pruned_chunk(
@@ -235,38 +179,31 @@ def block_scan_pruned_chunk(
     the last block — callers mask them (core/scan_backends.py masks by
     the stopping condition, which includes block_ptr < n_blocks).
     """
-    interpret = INTERPRET if interpret is None else interpret
-    b, nb, tf, w = occ.shape
-    t = n_terms
-    p_steps = tf
-    ncols = meta.shape[-1]
+    return _chunk_call(occ, meta, chunk=chunk, n_terms=n_terms,
+                       shared_occ=False, interpret=interpret)
 
-    def occ_map(bi, c, p, m):
-        blk = jnp.minimum(m[bi, 0, ncols - 1] + c, nb - 1)
-        return (bi, blk, m[bi, 0, p], 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, chunk, p_steps),
-        in_specs=[pl.BlockSpec((1, 1, 1, w), occ_map)],
-        out_specs=[
-            pl.BlockSpec((1, 1, w), lambda bi, c, p, m: (bi, c, 0)),
-            pl.BlockSpec((1, 1, 8), lambda bi, c, p, m: (bi, c, 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((t, w), jnp.uint32)],
-    )
-    kernel = functools.partial(_chunk_kernel, t=t, p_steps=p_steps)
-    match, counts = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, chunk, w), jnp.uint32),
-            jax.ShapeDtypeStruct((b, chunk, 8), jnp.int32),
-        ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
-        interpret=interpret,
-        name="block_scan_pruned_chunk",
-    )(meta, occ)
-    return match, counts[..., 0], counts[..., 1]
+def block_scan_pruned_pallas(
+    occ: jnp.ndarray,            # (n_blocks, T, F, W) uint32
+    allowed: np.ndarray,         # (T, F) bool — STATIC (host) rule mask
+    required: np.ndarray,        # (T,) bool — static
+    term_present: np.ndarray,    # (T,) bool — static
+    *,
+    chunk: int = 32,
+    interpret: bool | None = None,
+):
+    """Returns (match (nb, W) u32, v_inc (nb,) i32, n_match (nb,) i32).
+    Only the rule's active planes are ever read from HBM."""
+    nb, t, f, w = occ.shape
+    chunk = min(chunk, nb)
+    lanes = cdiv(nb, chunk)
+    meta = build_rule_meta(
+        jnp.broadcast_to(jnp.asarray(allowed, bool), (lanes, t, f)),
+        jnp.broadcast_to(jnp.asarray(required, bool), (lanes, t)),
+        jnp.broadcast_to(jnp.asarray(term_present, bool), (lanes, t)),
+        jnp.arange(lanes, dtype=jnp.int32) * chunk)
+    match, v_inc, n_match = _chunk_call(
+        occ.reshape(1, nb, t * f, w), meta, chunk=chunk, n_terms=t,
+        shared_occ=True, interpret=interpret)
+    return (match.reshape(lanes * chunk, w)[:nb],
+            v_inc.reshape(-1)[:nb], n_match.reshape(-1)[:nb])

@@ -8,37 +8,29 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["INTERPRET", "pad_axis_to", "cdiv", "NEG_INF", "tpu_compiler_params",
-           "reduce_or", "reduce_and"]
+__all__ = ["resolve_interpret", "pad_axis_to", "cdiv", "NEG_INF"]
 
-INTERPRET = jax.default_backend() != "tpu"
 NEG_INF = float("-inf")
 
-# Renamed TPUCompilerParams -> CompilerParams across jax releases.
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
 
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Decide at call time whether a Pallas kernel runs in interpret mode.
 
-def tpu_compiler_params(**kwargs):
-    return _COMPILER_PARAMS_CLS(**kwargs)
-
-
-# lax.reduce_or / lax.reduce_and sugar is missing from some jax releases;
-# the generic lax.reduce lowers identically (and runs in pallas interpret).
-def reduce_or(x: jnp.ndarray, axes) -> jnp.ndarray:
-    if hasattr(jax.lax, "reduce_or"):
-        return jax.lax.reduce_or(x, axes=tuple(axes))
-    return jax.lax.reduce(x, jnp.zeros((), x.dtype), jax.lax.bitwise_or,
-                          tuple(axes))
-
-
-def reduce_and(x: jnp.ndarray, axes) -> jnp.ndarray:
-    if hasattr(jax.lax, "reduce_and"):
-        return jax.lax.reduce_and(x, axes=tuple(axes))
-    ones = jnp.array(~jnp.zeros((), x.dtype))
-    return jax.lax.reduce(x, ones, jax.lax.bitwise_and, tuple(axes))
+    ``None`` compiles the kernel when a TPU is the default backend and
+    interprets it anywhere else (the CPU test path).  Asking for
+    interpret mode while a TPU is the default backend is an error: a
+    kernel that silently ran in the interpreter on the chip would make
+    every device measurement meaningless.
+    """
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise RuntimeError(
+            "Pallas interpret mode requested while a TPU is the default "
+            "backend; pass interpret=None (or False) on the chip")
+    return bool(interpret)
 
 
 def cdiv(a: int, b: int) -> int:

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import INTERPRET, NEG_INF, cdiv, tpu_compiler_params
+from repro.kernels.common import resolve_interpret, NEG_INF, cdiv
 
 __all__ = ["decode_attention_pallas"]
 
@@ -85,7 +85,7 @@ def decode_attention_pallas(
     """Returns (out (B, Hq, D), m (B, Hq, 1), l (B, Hq, 1)); if
     return_partial, ``out`` is the unnormalized accumulator for cross-
     shard LSE merging."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     b, hq, d = q.shape
     _, hkv, s, _ = k.shape
     group = hq // hkv
@@ -132,7 +132,7 @@ def decode_attention_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import INTERPRET, tpu_compiler_params
+from repro.kernels.common import resolve_interpret
 
 __all__ = ["embedding_bag_pallas"]
 
@@ -54,7 +54,7 @@ def embedding_bag_pallas(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Returns (B, E) pooled embeddings."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     assert mode in ("sum", "mean")
     b, l = indices.shape
     v, e = table.shape
@@ -84,7 +84,7 @@ def embedding_bag_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, e), table.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,

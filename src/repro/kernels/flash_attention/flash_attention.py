@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import INTERPRET, NEG_INF, cdiv, tpu_compiler_params
+from repro.kernels.common import resolve_interpret, NEG_INF, cdiv
 
 __all__ = ["flash_attention_pallas"]
 
@@ -97,7 +97,7 @@ def flash_attention_pallas(
     q_len: int | None = None,    # true (unpadded) q length (for the causal offset)
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     assert hq % hkv == 0, "GQA requires q_heads % kv_heads == 0"
@@ -138,7 +138,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -79,6 +79,9 @@ def _rand_doc(rng, vocab: int):
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--replicas", type=int, default=2)
     ap.add_argument("--publish-every", type=int, default=10,

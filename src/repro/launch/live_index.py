@@ -28,6 +28,9 @@ import numpy as np
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--replicas", type=int, default=2)
     ap.add_argument("--ticks", type=int, default=6,

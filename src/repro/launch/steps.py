@@ -456,8 +456,6 @@ def _build_recsys(arch: ArchDef, shape_name: str, mesh, reduced: bool) -> CellSp
 
 # ================================================================= websearch
 def _build_websearch(arch: ArchDef, shape_name: str, mesh, reduced: bool) -> CellSpec:
-    from jax.experimental.shard_map import shard_map
-
     from repro.core.environment import EnvConfig
     from repro.core.qlearning import QConfig, train_batch
     from repro.core.rollout import unified_rollout
@@ -511,18 +509,20 @@ def _build_websearch(arch: ArchDef, shape_name: str, mesh, reduced: bool) -> Cel
             gathered = jax.lax.all_gather(cand, "model")        # (S, Qloc, K)
             merged = merge_shard_candidates(gathered, keep=wcfg.max_candidates)
             u_tot = jax.lax.psum(final.u, "model")
-            return merged, u_tot, jax.lax.psum(final.cand_cnt, "model")
+            # Candidates that survive the merge, as ServeEngine's
+            # ShardedExecutor counts them.
+            return merged, u_tot, jnp.sum(merged >= 0, axis=1, dtype=jnp.int32)
 
         if mesh is None:
             fn = local_serve
         else:
-            fn = shard_map(
+            fn = jax.shard_map(
                 local_serve, mesh=mesh,
                 in_specs=(P(), StateBins(u_edges=P(), v_edges=P()),
                           P(dp, "model", None, None, None),
                           P(dp, "model"), P(dp, None)),
                 out_specs=(P(dp, None), P(dp), P(dp)),
-                check_rep=False,
+                check_vma=False,
             )
         args = (q_abs, bins_abs, occ_abs, scores_abs, tp_abs)
         in_sh = (_named(mesh, P()), _named(mesh, bins_specs), _named(mesh, occ_spec),
@@ -549,7 +549,7 @@ def _build_websearch(arch: ArchDef, shape_name: str, mesh, reduced: bool) -> Cel
         fn = lambda qt, bins, occ, scores, tp, prod_r, rng: local_train(
             qt, bins, occ, scores, tp, prod_r, rng)
     else:
-        fn = shard_map(
+        fn = jax.shard_map(
             local_train, mesh=mesh,
             in_specs=(P(), StateBins(u_edges=P(), v_edges=P()),
                       P(dp, "model", None, None, None), P(dp, "model"),
@@ -557,7 +557,7 @@ def _build_websearch(arch: ArchDef, shape_name: str, mesh, reduced: bool) -> Cel
             out_specs=(P(), jax.tree_util.tree_map(lambda _: P(),
                        {"mean_u": 0, "mean_v": 0, "mean_cand": 0,
                         "mean_reward": 0, "q_abs_mean": 0})),
-            check_rep=False,
+            check_vma=False,
         )
     rng_abs = jax.eval_shape(lambda: jax.random.key(0))
     args = (q_abs, bins_abs, occ_abs, scores_abs, tp_abs, prod_abs, rng_abs)

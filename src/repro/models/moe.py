@@ -136,7 +136,6 @@ def moe_ffn_sharded(params: Dict, x: jnp.ndarray, cfg: MoEConfig, mesh,
           combines experts also combines the ff partial sums.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     n_shards = mesh.shape[model_axis]
     fsdp = fsdp and "data" in mesh.shape
@@ -203,11 +202,11 @@ def moe_ffn_sharded(params: Dict, x: jnp.ndarray, cfg: MoEConfig, mesh,
             lambda _: P(model_axis), params["experts"]
         )
     xspec = P(data_axes) if data_axes else P()
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(pspec_params, xspec),
         out_specs=(xspec, P()),
-        check_rep=False,
+        check_vma=False,
     )(params, x)
 
 
@@ -216,7 +215,6 @@ def _moe_ffn_sharded_tp(params: Dict, x: jnp.ndarray, cfg: MoEConfig, mesh,
     """TP regime: every shard holds (E, d, d_ff/M) slices; capacity
     dispatch is identical on all shards, the psum combines ff partials."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     def local_fn(p_local, x_local):
         if fsdp:
@@ -260,9 +258,9 @@ def _moe_ffn_sharded_tp(params: Dict, x: jnp.ndarray, cfg: MoEConfig, mesh,
         "w_down": P(None, model_axis, d_ax),
     }
     xspec = P(data_axes) if data_axes else P()
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(pspec_params, xspec),
         out_specs=(xspec, P()),
-        check_rep=False,
+        check_vma=False,
     )(params, x)

@@ -111,7 +111,6 @@ def _layer_fwd_zero3(cfg: TransformerConfig, mesh, lp: Dict, x: jnp.ndarray):
     """ZeRO-3 dense block: weights stored P(data, model)-sharded, gathered
     HERE (inside the remat region → re-gathered in bwd), all math local
     over the batch shard — zero activation collectives."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     dp = tuple(a for a in ("pod", "data") if a in mesh.shape)
@@ -155,8 +154,8 @@ def _layer_fwd_zero3(cfg: TransformerConfig, mesh, lp: Dict, x: jnp.ndarray):
     if bx % n_ax != 0 or bx < n_ax:
         axes = dp
     xspec = P(axes, None, None)
-    out = shard_map(local_fn, mesh=mesh, in_specs=(lp_specs, xspec),
-                    out_specs=xspec, check_rep=False)(lp, x)
+    out = jax.shard_map(local_fn, mesh=mesh, in_specs=(lp_specs, xspec),
+                        out_specs=xspec, check_vma=False)(lp, x)
     return out, jnp.float32(0.0)
 
 

@@ -118,6 +118,15 @@ class ShardedExecutor:
     # ----------------------------------------------------------- the step
     def _serve_fn(self, bins, policy, occ, scores, term_present):
         """(B, NB, T, F, W) occupancy → (ids, scores, u, cand_cnt)."""
+        merged, u_tot, cand_cnt = self.merged_candidates(
+            bins, policy, occ, scores, term_present)
+        ids, sc = l1_prune(scores, merged, keep=self.keep)
+        return ids, sc, u_tot, cand_cnt
+
+    def merged_candidates(self, bins, policy, occ, scores, term_present):
+        """Per-shard rollouts merged by static rank, before L1 pruning:
+        (merged (B, max_candidates) global doc ids, u summed over shards,
+        merged candidate count)."""
         sys_ = self.system
         s, ds = self.n_shards, self.docs_per_shard
         b = occ.shape[0]
@@ -136,10 +145,9 @@ class ShardedExecutor:
         global_cand = jnp.where(final.cand >= 0, final.cand + shard_base, -1)
         merged = merge_shard_candidates(
             global_cand, keep=sys_.env_cfg.max_candidates)   # (B, K)
-        ids, sc = l1_prune(scores, merged, keep=self.keep)
         u_tot = jnp.sum(final.u, axis=0)
         cand_cnt = jnp.sum((merged >= 0).astype(jnp.int32), axis=1)
-        return ids, sc, u_tot, cand_cnt
+        return merged, u_tot, cand_cnt
 
     # ------------------------------------------------------------ compile
     @staticmethod

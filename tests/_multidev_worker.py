@@ -29,7 +29,9 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 results = {}
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+
+mesh = make_mesh((2, 4), ("data", "model"))
 
 # ---------------------------------------------------------- MoE EP parity
 from repro.models.moe import MoEConfig, moe_ffn, moe_ffn_sharded, moe_init

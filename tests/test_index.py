@@ -40,6 +40,40 @@ def test_corpus_field_structure(small):
     assert top_anchor > tail_anchor
 
 
+def test_corpus_chunks_from_workers_match_their_streams(monkeypatch):
+    """Chunks past the first come from worker processes; each must be
+    exactly what its own (seed, chunk) stream draws in this process,
+    the first must continue the corpus stream, and the field
+    structure must hold across chunk seams."""
+    from repro.index import corpus as corpus_mod
+
+    monkeypatch.setattr(corpus_mod, "DOC_CHUNK", 64)
+    cfg = CorpusConfig(n_docs=200, vocab_size=256, seed=5)
+    corpus = generate_corpus(cfg)
+    assert all(len(corpus.field_terms[f]) == 200 for f in range(N_FIELDS))
+    for i, lo in enumerate(range(0, 200, 64)):
+        sl = slice(lo, lo + 64)
+        if i == 0:      # the corpus stream, after its global draws
+            rng = np.random.default_rng(cfg.seed)
+            for _ in range(cfg.n_topics):
+                rng.choice(np.arange(cfg.vocab_size // 4, cfg.vocab_size),
+                           size=corpus.topic_terms.shape[1], replace=False)
+            rng.exponential(size=cfg.n_docs)
+            rng.integers(0, cfg.n_topics, size=cfg.n_docs)
+        else:
+            rng = np.random.default_rng([cfg.seed, i])
+        ref = corpus_mod._generate_docs(cfg, rng, corpus.topic_terms,
+                                        corpus.static_rank[sl],
+                                        corpus.doc_topic[sl])
+        for f in range(N_FIELDS):
+            got = corpus.field_terms[f][sl]
+            assert len(got) == len(ref[f])
+            assert all(np.array_equal(a, b) and a.dtype == b.dtype
+                       for a, b in zip(got, ref[f]))
+    for d in range(200):
+        assert np.isin(corpus.field_terms[U][d], corpus.field_terms[T][d]).all()
+
+
 def test_static_rank_sorted(small):
     corpus, _ = small
     assert (np.diff(corpus.static_rank) <= 0).all()

@@ -253,3 +253,50 @@ def test_block_scan_pruned_chunk_vs_ref():
             assert (np.asarray(m[lane, j]) == np.asarray(mr[0])).all()
             assert int(v[lane, j]) == int(vr[0])
             assert int(c[lane, j]) == int(cr[0])
+
+
+def test_block_scan_pruned_chunk_reads_only_active_planes():
+    """Bytes streamed follow the rule's active planes: the meta lists
+    exactly the active (term, field) planes first, one DMA each, and
+    whatever sits in the inactive planes never reaches the result."""
+    from repro.core.match_rules import block_cost
+    from repro.kernels.block_scan.block_scan_pruned import (
+        META_BP_COL, block_scan_pruned_chunk, build_rule_meta,
+    )
+
+    rng = np.random.default_rng(11)
+    b, nb, t, f, w, chunk = 4, 6, 4, 4, 16, 3
+    allowed = jnp.asarray(rng.random((b, t, f)) < 0.4)
+    allowed = allowed.at[3].set(False)               # zero-active lane
+    required = jnp.asarray(rng.random((b, t)) < 0.6)
+    present = jnp.asarray(rng.random((b, t)) < 0.9)
+    meta = build_rule_meta(allowed, required, present,
+                           jnp.asarray([0, 2, 3, 5], jnp.int32))
+    act = np.asarray(allowed & present[:, :, None]).reshape(b, t * f)
+    for lane in range(b):
+        n_active = int(meta[lane, 1, META_BP_COL])
+        assert n_active == int(block_cost(allowed[lane], present[lane]))
+        assert sorted(np.asarray(meta[lane, 0, :n_active]).tolist()) == \
+            np.flatnonzero(act[lane]).tolist()
+
+    occ = rng.integers(0, 2**32, (b, nb, t * f, w), dtype=np.uint32)
+    noise = rng.integers(0, 2**32, occ.shape, dtype=np.uint32)
+    occ_noisy = np.where(act[:, None, :, None], occ, noise)
+    outs = [block_scan_pruned_chunk(jnp.asarray(o), meta, chunk=chunk, n_terms=t)
+            for o in (occ, occ_noisy)]
+    for a, c in zip(*outs):
+        assert (np.asarray(a) == np.asarray(c)).all()
+
+
+def test_resolve_interpret_is_decided_at_call_time(monkeypatch):
+    """Interpret mode follows the backend at CALL time; on a TPU it is
+    never chosen implicitly, and asking for it there is an error."""
+    from repro.kernels.common import resolve_interpret
+
+    assert resolve_interpret(None) is (jax.default_backend() != "tpu")
+    assert resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    assert resolve_interpret(False) is False
+    with pytest.raises(RuntimeError, match="interpret mode"):
+        resolve_interpret(True)

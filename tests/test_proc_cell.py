@@ -499,3 +499,28 @@ def test_oplog_checkpoint_restore_bit_parity(tmp_path):
         for term in range(vocab):
             np.testing.assert_array_equal(av.postings(term, f),
                                           bv.postings(term, f))
+
+
+# ------------------------------------------------- one process per chip
+def test_process_backend_refuses_on_tpu(tiny_system, monkeypatch):
+    """A TPU belongs to one process, and the parent already holds it:
+    with a TPU default backend the process cell raises before it
+    creates any worker (or even its rings)."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def no_worker(*a, **k):
+        raise AssertionError("a process replica was created on a TPU host")
+
+    monkeypatch.setattr(ProcessReplica, "__init__", no_worker)
+    store = _store({cat: TabularQPolicy(np.zeros((4, 4), np.float32))
+                    for cat in (CAT1, CAT2)})
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        ReplicaSet(tiny_system, store, ClusterConfig(backend="process"))
+    monkeypatch.undo()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pr = ProcessReplica(0, spec_factory=None, keep=4)
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        pr.start()
+    assert pr._proc is None and pr._req is None and pr.worker_pid is None
