@@ -19,12 +19,14 @@ from typing import List, Sequence
 
 import numpy as np
 
+from repro.obs import NULL_SPAN
+
 from .blocks import WORD_BITS, pack_bits, words_per_block
 from .corpus import Corpus, N_FIELDS
 
 __all__ = ["InvertedIndex", "build_index", "build_index_from_pairs",
            "forward_csr", "query_occupancy", "batch_query_occupancy",
-           "MAX_QUERY_TERMS"]
+           "pack_occupancy", "MAX_QUERY_TERMS"]
 
 MAX_QUERY_TERMS = 4  # queries are padded to this many terms
 
@@ -164,26 +166,38 @@ def forward_csr(index: InvertedIndex):
     return fwd_indptrs, fwd_terms
 
 
-def query_occupancy(index: InvertedIndex, terms: Sequence[int]) -> np.ndarray:
-    """Build ``occ[block, term, field, word]`` uint32 for one query.
+def query_occupancy(index: InvertedIndex, terms: Sequence[int],
+                    span=NULL_SPAN) -> np.ndarray:
+    """Build ``occ[block, term, field, word]`` uint32 for one query:
+    its postings set in bool planes over every document (span
+    ``scatter``), then packed (:func:`pack_occupancy`).
 
     ``terms`` may be shorter than MAX_QUERY_TERMS; missing slots are
     all-zero planes (the match engine masks them out via the query's
     term-count).
     """
-    n_pad = index.padded_docs
-    occ_bits = np.zeros((MAX_QUERY_TERMS, N_FIELDS, n_pad), dtype=bool)
-    for t, term in enumerate(terms[:MAX_QUERY_TERMS]):
-        for f in range(N_FIELDS):
-            ids = index.postings(int(term), f)
-            occ_bits[t, f, ids] = True
-    packed = pack_bits(occ_bits)                      # (T, F, n_pad/32)
-    W = words_per_block(index.block_docs)
-    n_blocks = index.n_blocks
-    packed = packed.reshape(MAX_QUERY_TERMS, N_FIELDS, n_blocks, W)
-    return np.ascontiguousarray(packed.transpose(2, 0, 1, 3))  # (block, T, F, W)
+    with span.child("scatter"):
+        occ_bits = np.zeros((MAX_QUERY_TERMS, N_FIELDS, index.padded_docs),
+                            dtype=bool)
+        for t, term in enumerate(terms[:MAX_QUERY_TERMS]):
+            for f in range(N_FIELDS):
+                occ_bits[t, f, index.postings(int(term), f)] = True
+    return pack_occupancy(occ_bits, index.n_blocks,
+                          words_per_block(index.block_docs), span)
 
 
-def batch_query_occupancy(index: InvertedIndex, term_lists: Sequence[Sequence[int]]) -> np.ndarray:
+def pack_occupancy(occ_bits: np.ndarray, n_blocks: int, words: int,
+                   span=NULL_SPAN) -> np.ndarray:
+    """(T, F, n_docs) bool planes -> ``occ[block, term, field, word]``
+    uint32: bit-packed and laid out block-major (span ``pack``)."""
+    with span.child("pack"):
+        packed = pack_bits(occ_bits)                  # (T, F, n_docs/32)
+        packed = packed.reshape(MAX_QUERY_TERMS, N_FIELDS, n_blocks, words)
+        return np.ascontiguousarray(packed.transpose(2, 0, 1, 3))
+
+
+def batch_query_occupancy(index: InvertedIndex,
+                          term_lists: Sequence[Sequence[int]],
+                          span=NULL_SPAN) -> np.ndarray:
     """Stack per-query occupancy tensors: (Q, block, T, F, W) uint32."""
-    return np.stack([query_occupancy(index, ts) for ts in term_lists])
+    return np.stack([query_occupancy(index, ts, span) for ts in term_lists])

@@ -27,11 +27,11 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.versioned import StaleVersionError, VersionedStore
-from repro.index.blocks import pack_bits, words_per_block
+from repro.index.blocks import words_per_block
 from repro.index.builder import (InvertedIndex, MAX_QUERY_TERMS,
-                                 build_index_from_pairs)
+                                 build_index_from_pairs, pack_occupancy)
 from repro.index.corpus import N_FIELDS
-from repro.obs import NULL_TRACER, MetricsRegistry, Tracer
+from repro.obs import NULL_SPAN, NULL_TRACER, MetricsRegistry, Tracer
 
 from .segments import BaseSegment, DeltaOp, DeltaSegment, _canon_fields
 
@@ -123,37 +123,38 @@ class IndexView:
                 for f in range(N_FIELDS)]
 
     # --------------------------------------------------------- occupancy
-    def query_occupancy(self, terms: Sequence[int]) -> np.ndarray:
+    def query_occupancy(self, terms: Sequence[int],
+                        span=NULL_SPAN) -> np.ndarray:
         """``occ[block, term, field, word]`` uint32 over the FIXED
         capacity: base planes (tombstones masked) unioned with delta
         planes.  Both scan backends consume the union unchanged, so
         candidates from either segment merge inside the ordinary
         block scan."""
-        occ_bits = np.zeros((MAX_QUERY_TERMS, N_FIELDS, self.capacity_docs),
-                            dtype=bool)
-        base_bytes = delta_bytes = 0
-        tomb = self.delta.tombstones.size > 0
-        for t, term in enumerate(terms[:MAX_QUERY_TERMS]):
-            for f in range(N_FIELDS):
-                ids = self.base.index.postings(int(term), f)
-                base_bytes += ids.nbytes
-                if tomb:
-                    ids = ids[~self.delta.tomb_mask[ids]]
-                occ_bits[t, f, ids] = True
-                d_ids = self.delta.postings(int(term), f)
-                if d_ids.size:
-                    delta_bytes += d_ids.nbytes
-                    occ_bits[t, f, d_ids] = True
-        if self._account is not None:
-            self._account(base_bytes, delta_bytes)
-        packed = pack_bits(occ_bits)          # (T, F, capacity/32)
-        packed = packed.reshape(MAX_QUERY_TERMS, N_FIELDS,
-                                self.capacity_blocks, self.words)
-        return np.ascontiguousarray(packed.transpose(2, 0, 1, 3))
+        with span.child("scatter"):
+            occ_bits = np.zeros((MAX_QUERY_TERMS, N_FIELDS,
+                                 self.capacity_docs), dtype=bool)
+            base_bytes = delta_bytes = 0
+            tomb = self.delta.tombstones.size > 0
+            for t, term in enumerate(terms[:MAX_QUERY_TERMS]):
+                for f in range(N_FIELDS):
+                    ids = self.base.index.postings(int(term), f)
+                    base_bytes += ids.nbytes
+                    if tomb:
+                        ids = ids[~self.delta.tomb_mask[ids]]
+                    occ_bits[t, f, ids] = True
+                    d_ids = self.delta.postings(int(term), f)
+                    if d_ids.size:
+                        delta_bytes += d_ids.nbytes
+                        occ_bits[t, f, d_ids] = True
+            if self._account is not None:
+                self._account(base_bytes, delta_bytes)
+        return pack_occupancy(occ_bits, self.capacity_blocks, self.words,
+                              span)
 
-    def batch_query_occupancy(self,
-                              term_lists: Sequence[Sequence[int]]) -> np.ndarray:
-        return np.stack([self.query_occupancy(ts) for ts in term_lists])
+    def batch_query_occupancy(self, term_lists: Sequence[Sequence[int]],
+                              span=NULL_SPAN) -> np.ndarray:
+        return np.stack([self.query_occupancy(ts, span)
+                         for ts in term_lists])
 
     def describe(self) -> dict:
         return {"n_docs": self.n_docs, "capacity_docs": self.capacity_docs,

@@ -26,13 +26,12 @@ import threading
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.index.builder import MAX_QUERY_TERMS
-from repro.obs import NULL_TRACER, MetricsRegistry, Tracer
-from repro.ranking.l1_ranker import idf_for_terms, score_all_docs
+from repro.obs import NULL_SPAN, NULL_TRACER, MetricsRegistry, Tracer
+from repro.ranking.l1_ranker import idf_for_terms
 from repro.system import RetrievalSystem, SystemConfig
 
 from .live_index import IndexEpoch, LiveIndex
@@ -86,25 +85,16 @@ class EpochReadMixin:
 
     # ------------------------------------------------------------ batches
     def batch_inputs(self, query_ids: Sequence[int],
-                     epoch: Optional[IndexEpoch] = None):
+                     epoch: Optional[IndexEpoch] = None,
+                     span=NULL_SPAN):
         """Occupancy + L1 scores + masks at one pinned index epoch
         (head epoch when omitted — single-threaded callers)."""
         if epoch is None:
             epoch = self.index_epoch_store.snapshot()
-        view = epoch.view
-        qids = np.asarray(query_ids)
-        log = self.log                      # capture refs: appends swap
-        idf_all = self.idf_all              # whole arrays, never resize
-        term_lists = [log.terms[q, : log.n_terms[q]] for q in qids]
-        occ = jnp.asarray(view.batch_query_occupancy(term_lists))
-        term_present = jnp.asarray(log.terms[qids] >= 0)
-        idf = jnp.asarray(idf_all[qids])
         static_rank, doc_len = self._epoch_planes(epoch)
-        scores = jax.vmap(
-            lambda o, i, t: score_all_docs(
-                self.l1_params, o, i, t, static_rank, doc_len)
-        )(occ, idf, term_present)
-        return occ, scores, term_present
+        return self._device_inputs(query_ids,
+                                   epoch.view.batch_query_occupancy,
+                                   static_rank, doc_len, span)
 
 
 class LiveRetrievalSystem(EpochReadMixin, RetrievalSystem):

@@ -26,7 +26,7 @@ from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
-from repro.obs import NULL_TRACER, Tracer
+from repro.obs import NULL_SPAN, NULL_TRACER, Tracer
 from repro.policies import Policy, PolicyStore
 from repro.serving.array_cache import ArrayResultCache
 from repro.serving.batcher import (
@@ -428,78 +428,80 @@ class ServeEngine:
         self.store.validate(self._snapshot.version)
         if self._index_store is not None:
             self._index_store.validate(self.index_epoch)
+        # Ended with an error arg if anything below raises, so its
+        # profiler annotation never outlives the slab.
         slab_span = (self.tracer.span("slab", n=n) if spans is None
-                     else None)
-        t0 = Telemetry.now()
-        rid0 = self._next_id
-        self._next_id += n
-        rids = np.arange(rid0, rid0 + n, dtype=np.int64)
-        statuses = np.zeros(n, np.uint8)
-        version = self._snapshot.version
-        epoch = self.index_epoch
-        key_of = self._key_cache.key
-        cache = self.cache
-        pend0 = self.batcher.pending()
-        limit = self.cfg.admission_limit
-        cached_only = int(ServiceLevel.CACHED_ONLY)
-        hits = []                       # (i, category, entry)
-        pending: List[PendingRequest] = []
-        queued = 0
-        n_rej = 0
-        for i in range(n):
-            qid = int(slab.qids[i])
-            cat = int(slab.categories[i])
-            req_level = int(lv[i])
-            key = key_of(qid, cat)
-            entry = cache.peek((key, version, epoch))
-            if entry is not None and int(entry.level) <= req_level:
-                cache.touch((key, version, epoch))
-                hits.append((i, cat, entry))
-                continue
-            if req_level == cached_only:
-                statuses[i] = SLAB_CACHED_ONLY_MISS
-                continue
-            if pend0 + queued >= limit:
-                statuses[i] = SLAB_ADMISSION_REJECT
-                n_rej += 1
-                continue
-            queued += 1
-            span = spans[i] if spans is not None else None
-            pending.append(PendingRequest(
-                request_id=int(rids[i]), qid=qid, category=cat,
-                cache_key=key, t_submit=t0, level=req_level, span=span,
-                queue_span=span.child("queue", category=cat,
-                                      level=req_level) if span else None,
-                own_span=False))
-        t1 = Telemetry.now()
-        # Hits complete as a group: same responses a scalar loop would
-        # produce (identical doc ids / scores / u — latency is the slab
-        # probe's), telemetry recorded one (level, category) cell at a
-        # time through pre-resolved handles.
-        if hits:
-            groups: Dict[tuple, list] = {}
-            for i, cat, entry in hits:
-                self._complete(ServeResponse(
-                    request_id=int(rids[i]), qid=int(slab.qids[i]),
-                    category=cat, doc_ids=entry.doc_ids,
-                    scores=entry.scores, u=entry.u,
-                    cand_cnt=entry.cand_cnt, cached=True,
-                    latency_s=t1 - t0, policy_version=version,
-                    index_epoch=epoch, level=entry.level))
-                groups.setdefault((int(entry.level), cat),
-                                  []).append(entry.u)
-            for (lvl, cat), us in groups.items():
-                self.telemetry.record_requests(
-                    category=cat, level=lvl,
-                    latencies_s=np.full(len(us), t1 - t0), us=us,
-                    cached=True, t_done=t1)
-        cache.add_stats(hits=len(hits), misses=n - len(hits))
-        if n_rej:
-            self.telemetry.record_rejection(n_rej)
-        if pending:
-            self.batcher.enqueue_many(pending)
-        self.telemetry.observe_gauges(self.queue_depth, self._inflight)
-        if slab_span:
+                     else NULL_SPAN)
+        with slab_span:
+            t0 = Telemetry.now()
+            rid0 = self._next_id
+            self._next_id += n
+            rids = np.arange(rid0, rid0 + n, dtype=np.int64)
+            statuses = np.zeros(n, np.uint8)
+            version = self._snapshot.version
+            epoch = self.index_epoch
+            key_of = self._key_cache.key
+            cache = self.cache
+            pend0 = self.batcher.pending()
+            limit = self.cfg.admission_limit
+            cached_only = int(ServiceLevel.CACHED_ONLY)
+            hits = []                       # (i, category, entry)
+            pending: List[PendingRequest] = []
+            queued = 0
+            n_rej = 0
+            for i in range(n):
+                qid = int(slab.qids[i])
+                cat = int(slab.categories[i])
+                req_level = int(lv[i])
+                key = key_of(qid, cat)
+                entry = cache.peek((key, version, epoch))
+                if entry is not None and int(entry.level) <= req_level:
+                    cache.touch((key, version, epoch))
+                    hits.append((i, cat, entry))
+                    continue
+                if req_level == cached_only:
+                    statuses[i] = SLAB_CACHED_ONLY_MISS
+                    continue
+                if pend0 + queued >= limit:
+                    statuses[i] = SLAB_ADMISSION_REJECT
+                    n_rej += 1
+                    continue
+                queued += 1
+                span = spans[i] if spans is not None else None
+                pending.append(PendingRequest(
+                    request_id=int(rids[i]), qid=qid, category=cat,
+                    cache_key=key, t_submit=t0, level=req_level, span=span,
+                    queue_span=span.child("queue", category=cat,
+                                          level=req_level) if span else None,
+                    own_span=False))
+            t1 = Telemetry.now()
+            # Hits complete as a group: same responses a scalar loop would
+            # produce (identical doc ids / scores / u — latency is the slab
+            # probe's), telemetry recorded one (level, category) cell at a
+            # time through pre-resolved handles.
+            if hits:
+                groups: Dict[tuple, list] = {}
+                for i, cat, entry in hits:
+                    self._complete(ServeResponse(
+                        request_id=int(rids[i]), qid=int(slab.qids[i]),
+                        category=cat, doc_ids=entry.doc_ids,
+                        scores=entry.scores, u=entry.u,
+                        cand_cnt=entry.cand_cnt, cached=True,
+                        latency_s=t1 - t0, policy_version=version,
+                        index_epoch=epoch, level=entry.level))
+                    groups.setdefault((int(entry.level), cat),
+                                      []).append(entry.u)
+                for (lvl, cat), us in groups.items():
+                    self.telemetry.record_requests(
+                        category=cat, level=lvl,
+                        latencies_s=np.full(len(us), t1 - t0), us=us,
+                        cached=True, t_done=t1)
+            cache.add_stats(hits=len(hits), misses=n - len(hits))
+            if n_rej:
+                self.telemetry.record_rejection(n_rej)
+            if pending:
+                self.batcher.enqueue_many(pending)
+            self.telemetry.observe_gauges(self.queue_depth, self._inflight)
             slab_span.end(hits=len(hits), queued=queued, rejected=n_rej)
         return rids, statuses
 
@@ -567,15 +569,17 @@ class ServeEngine:
         # merge publishes mid-execution (the next drain adopts it).
         epoch_snap = self._index_epoch_snap
         epoch_version = epoch_snap.version if epoch_snap is not None else 0
-        if self._index_store is not None:
-            self._index_store.validate(epoch_version)
         try:
+            if self._index_store is not None:
+                self._index_store.validate(epoch_version)
             qids = mb.padded_qids()
-            occ, scores, tp = self.system.batch_inputs(qids,
-                                                       epoch=epoch_snap)
+            with mb_span.child("batch_inputs") as span:
+                occ, scores, tp = self.system.batch_inputs(
+                    qids, epoch=epoch_snap, span=span)
             t1 = Telemetry.now()
-            ids, sc, u, cnt = self.executor.execute(
-                policy, occ, scores, tp, level=int(level))
+            with mb_span.child("execute") as span:
+                ids, sc, u, cnt = self.executor.execute(
+                    policy, occ, scores, tp, level=int(level), span=span)
             t2 = Telemetry.now()
         except Exception as err:
             mb_span.end(error=type(err).__name__)
@@ -583,13 +587,9 @@ class ServeEngine:
         finally:
             self._inflight = 0
             self.telemetry.observe_gauges(self.queue_depth, 0)
-        if mb_span:
-            mb_span.child_at("batch_inputs", t0, t1)
-            mb_span.child_at("execute", t1, t2)
         version = self._snapshot.version
         self.telemetry.record_batch(category=mb.category, bucket=mb.bucket,
-                                    n_real=mb.n_real, t_inputs_s=t1 - t0,
-                                    t_execute_s=t2 - t1)
+                                    n_real=mb.n_real, t_inputs_s=t1 - t0)
         # Padded lanes (>= n_real) are dropped here: never cached, never
         # answered — the bucket-padding invariant the tests pin down.
         for lane, req in enumerate(mb.requests):

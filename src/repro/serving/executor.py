@@ -44,7 +44,7 @@ from repro.core.rollout import unified_rollout
 from repro.core.scan_backends import available_backends as scan_backends
 from repro.core.telescope import l1_prune, merge_shard_candidates
 from repro.index.corpus import N_FIELDS
-from repro.obs import NULL_TRACER
+from repro.obs import NULL_SPAN, NULL_TRACER
 from repro.policies import Policy
 
 __all__ = ["ShardedExecutor", "available_backends",
@@ -110,17 +110,19 @@ class ShardedExecutor:
         self._jit = jax.jit(self._serve_fn)
         self._compiled: Dict[tuple, jax.stages.Compiled] = {}
         self.compile_count = 0
-        self.execute_count = 0
         # Set by the owning engine when tracing is on; compiles are the
         # dominant cold-start latency, so each gets its own span.
         self.tracer = NULL_TRACER
 
     # ----------------------------------------------------------- the step
     def _serve_fn(self, bins, policy, occ, scores, term_present):
-        """(B, NB, T, F, W) occupancy → (ids, scores, u, cand_cnt)."""
+        """(B, NB, T, F, W) occupancy → (ids, scores, u, cand_cnt).
+        Named scopes ``rollout``, ``merge`` and ``l1_prune`` label its
+        device ops in a profiler trace."""
         merged, u_tot, cand_cnt = self.merged_candidates(
             bins, policy, occ, scores, term_present)
-        ids, sc = l1_prune(scores, merged, keep=self.keep)
+        with jax.named_scope("l1_prune"):
+            ids, sc = l1_prune(scores, merged, keep=self.keep)
         return ids, sc, u_tot, cand_cnt
 
     def merged_candidates(self, bins, policy, occ, scores, term_present):
@@ -139,14 +141,17 @@ class ShardedExecutor:
             return self._backend_fn(self.shard_env_cfg, sys_.ruleset, bins,
                                     policy, t_max, o, sc, term_present)
 
-        final = jax.vmap(one_shard)(occ_sh, scores_sh)
+        with jax.named_scope("rollout"):
+            final = jax.vmap(one_shard)(occ_sh, scores_sh)
 
-        shard_base = (jnp.arange(s, dtype=jnp.int32) * ds)[:, None, None]
-        global_cand = jnp.where(final.cand >= 0, final.cand + shard_base, -1)
-        merged = merge_shard_candidates(
-            global_cand, keep=sys_.env_cfg.max_candidates)   # (B, K)
-        u_tot = jnp.sum(final.u, axis=0)
-        cand_cnt = jnp.sum((merged >= 0).astype(jnp.int32), axis=1)
+        with jax.named_scope("merge"):
+            shard_base = (jnp.arange(s, dtype=jnp.int32) * ds)[:, None, None]
+            global_cand = jnp.where(final.cand >= 0,
+                                    final.cand + shard_base, -1)
+            merged = merge_shard_candidates(
+                global_cand, keep=sys_.env_cfg.max_candidates)   # (B, K)
+            u_tot = jnp.sum(final.u, axis=0)
+            cand_cnt = jnp.sum((merged >= 0).astype(jnp.int32), axis=1)
         return merged, u_tot, cand_cnt
 
     # ------------------------------------------------------------ compile
@@ -205,13 +210,18 @@ class ShardedExecutor:
 
     # ------------------------------------------------------------ execute
     def execute(self, policy: Policy, occ, scores, term_present,
-                level: int = 0
+                level: int = 0, span=NULL_SPAN
                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-        """Run one micro-batch through its pre-compiled executable."""
+        """Run one micro-batch through its pre-compiled executable:
+        ``dispatch`` the call, ``device_wait`` until its outputs (and
+        the inputs' device work before them) are done, ``d2h`` the
+        copies back, each a child of ``span``."""
         exe = self.compiled_for(occ.shape[0], policy, level)
-        ids, sc, u, cnt = exe(self.system.bins, policy, occ, scores,
-                              term_present)
-        jax.block_until_ready(ids)
-        self.execute_count += 1
-        return (np.asarray(ids), np.asarray(sc), np.asarray(u),
-                np.asarray(cnt))
+        with span.child("dispatch"):
+            ids, sc, u, cnt = exe(self.system.bins, policy, occ, scores,
+                                  term_present)
+        with span.child("device_wait"):
+            jax.block_until_ready(ids)
+        with span.child("d2h"):
+            return (np.asarray(ids), np.asarray(sc), np.asarray(u),
+                    np.asarray(cnt))

@@ -235,14 +235,13 @@ class Telemetry:
             wait_s * 1e3)
 
     def record_batch(self, *, category: int, bucket: int, n_real: int,
-                     t_inputs_s: float, t_execute_s: float) -> None:
+                     t_inputs_s: float) -> None:
         self.batches.append({
             "category": int(category),
             "bucket": int(bucket),
             "n_real": int(n_real),
             "n_padded": int(bucket - n_real),
             "t_inputs_s": float(t_inputs_s),
-            "t_execute_s": float(t_execute_s),
         })
         self._summary_dirty = True
 

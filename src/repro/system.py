@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from functools import partial
 from typing import Dict, Optional, Sequence
 
 import jax
@@ -22,6 +23,7 @@ from repro.core.qlearning import QConfig, init_q, linear_epsilon, train_batch
 from repro.core.reward import r_agent
 from repro.core.rollout import unified_rollout
 from repro.core.state_bins import StateBins, fit_bins
+from repro.obs import NULL_SPAN
 from repro.policies import PolicyStore, StaticPlanPolicy, TabularQPolicy
 from repro.data.querylog import CAT1, CAT2, QueryLog, QueryLogConfig, generate_querylog
 from repro.index.builder import InvertedIndex, batch_query_occupancy, build_index
@@ -107,21 +109,42 @@ class RetrievalSystem:
         self.build_time = time.time() - t0
 
     # ---------------------------------------------------------------- batches
-    def batch_inputs(self, query_ids: Sequence[int], epoch=None):
+    def batch_inputs(self, query_ids: Sequence[int], epoch=None,
+                     span=NULL_SPAN):
         """Occupancy + L1 scores + masks for a set of query ids.
 
         ``epoch`` exists for signature parity with the live system's
         epoch-pinned batches; the static index ignores it."""
+        return self._device_inputs(
+            query_ids, partial(batch_query_occupancy, self.index),
+            self.static_rank, self.doc_len, span)
+
+    def _device_inputs(self, query_ids: Sequence[int], occupancy,
+                       static_rank, doc_len, span=NULL_SPAN):
+        """``(occ, scores, term_present)`` of one batch, as children of
+        ``span``: ``occupancy(term_lists, span)`` builds the host bitmaps
+        (span ``occupancy``), which are copied to the device with the
+        term mask and idf (``h2d``, arg ``bytes``), and the eager L1
+        scoring of every document is enqueued (``l1_dispatch``)."""
         qids = np.asarray(query_ids)
-        term_lists = [self.log.terms[q, : self.log.n_terms[q]] for q in qids]
-        occ = jnp.asarray(batch_query_occupancy(self.index, term_lists))
-        term_present = jnp.asarray(self.log.terms[qids] >= 0)
-        idf = jnp.asarray(self.idf_all[qids])
-        scores = jax.vmap(
-            lambda o, i, t: score_all_docs(
-                self.l1_params, o, i, t, self.static_rank, self.doc_len
-            )
-        )(occ, idf, term_present)
+        log = self.log                      # capture refs: live appends swap
+        idf_all = self.idf_all              # whole arrays, never resize
+        term_lists = [log.terms[q, : log.n_terms[q]] for q in qids]
+        with span.child("occupancy", n_queries=len(qids)) as occ_span:
+            occ_host = occupancy(term_lists, occ_span)
+        present_host = log.terms[qids] >= 0
+        idf_host = idf_all[qids]
+        with span.child("h2d", bytes=occ_host.nbytes + present_host.nbytes
+                        + idf_host.nbytes):
+            occ = jnp.asarray(occ_host)
+            term_present = jnp.asarray(present_host)
+            idf = jnp.asarray(idf_host)
+        with span.child("l1_dispatch"):
+            params = self.l1_params
+            scores = jax.vmap(
+                lambda o, i, t: score_all_docs(params, o, i, t, static_rank,
+                                               doc_len)
+            )(occ, idf, term_present)
         return occ, scores, term_present
 
     def judged(self, query_ids: Sequence[int]):
