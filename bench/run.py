@@ -107,6 +107,11 @@ class Harness:
         seq = loadgen.plan_queries(log_.terms, log_.category,
                                    log_.popularity, traffic, self.seed,
                                    exclude=self.used)
+        need = int(self.cell.config.get("served_log", {}).get("min_unique", 0))
+        if len(seq) < need:
+            raise ValueError(
+                f"the query log plans {len(seq)} unique queries, under the "
+                f"{need} its configuration's served_log.min_unique asks for")
         rows = [r.engine.telemetry.batches for r in self.rs.replicas]
         n0 = [len(r) for r in rows]
         state = {}
@@ -145,6 +150,7 @@ class Harness:
             generator_lateness_s={"max": float(np.max(lat, initial=0.0)),
                                   "p95": loadgen.nearest_rank(lat, 0.95)},
             log_queries_per_sent=log_.n_queries / max(len(window.records), 1),
+            unique_supply=len(seq),
             host_cpu=state["cpu"].reading())
         return window, batches, trace_dir
 

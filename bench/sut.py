@@ -4,7 +4,10 @@ The program builds the corpus, the index and the query log from the
 seed; the benchmark then installs the tables it made
 (``bench/weights.py``) in place of the program's own fits, so that the
 plain reference can serve the same queries without taking anything the
-program made.  The window drives ``ReplicaSet.submit``: admission,
+program made.  Where the configuration has a ``served_log`` section, the
+benchmark also extends the program's judged log with unjudged queries
+(``extend_log``), so that a fast server never runs out of queries it
+has not seen.  The window drives ``ReplicaSet.submit``: admission,
 router, a replica's worker thread, the engine's batcher and result
 cache, ``System.batch_inputs`` and the AOT serve executable.
 """
@@ -29,6 +32,7 @@ def build_system(cfg: dict, seed: int, weights: dict):
     from repro.data.querylog import QueryLogConfig
     from repro.index.corpus import CorpusConfig
     from repro.policies import PolicyStore, TabularQPolicy
+    from repro.ranking.l1_ranker import idf_for_terms
     from repro.system import RetrievalSystem, SystemConfig
 
     w = cfg["widths"]
@@ -42,6 +46,11 @@ def build_system(cfg: dict, seed: int, weights: dict):
         t_max=int(w["t_max"]), l1_hidden=int(w["l1_hidden"]), seed=seed,
         backend=cfg["engine"]["backend"]))
     check_rules(sys_.ruleset, cfg["rules"])
+    if "served_log" in cfg:
+        sys_.log = extend_log(sys_.log, sys_.corpus,
+                              int(cfg["served_log"]["n_queries"]), cfg, seed)
+        sys_.idf_all = idf_for_terms(sys_.index.df[:, 2].astype(np.float64),
+                                     sys_.index.n_docs, sys_.log.terms)
     sys_.l1_params = {k: jnp.asarray(v) for k, v in weights["l1"].items()}
     sys_.bins = StateBins(u_edges=jnp.asarray(weights["u_edges"]),
                           v_edges=jnp.asarray(weights["v_edges"]))
@@ -51,6 +60,72 @@ def build_system(cfg: dict, seed: int, weights: dict):
     store.publish({cat: TabularQPolicy(jnp.asarray(weights["q"][cat]))
                    for cat in range(int(cfg["n_categories"]))})
     return sys_, store
+
+
+def extend_log(log, corpus, n_queries: int, cfg: dict, seed: int):
+    """``log`` followed by unjudged queries up to ``n_queries`` in all.
+
+    The extension follows the sampling law of the program's
+    ``repro.data.querylog.generate_querylog``, from its own stream of the
+    seed: a CAT2 query takes 2-3 terms of the title and url terms of one
+    of the ``max(64, n_docs // 16)`` best-ranked documents; a CAT1 query
+    takes 3 to ``MAX_QUERY_TERMS`` terms of a random document's body
+    terms within its topic, or of its body terms where fewer than 2 are
+    topical.  The program's generator cannot be reused: it draws each
+    query's terms and judgements from one stream in turn, and it judges
+    every document for every query, which would cost set-up minutes.
+    Extension queries are not judged (``judged_ids`` -1,
+    ``judged_gains`` 0); nothing served reads judgements.  Popularity is
+    the program's Zipf over the whole log, CAT2 queries first.
+    """
+    from repro.data.querylog import CAT1, CAT2, QueryLog
+    from repro.index.builder import MAX_QUERY_TERMS
+    from repro.index.corpus import B, T, U
+
+    n = n_queries - log.n_queries
+    if n < 0:
+        raise ValueError(f"served_log.n_queries {n_queries} is below the "
+                         f"judged log's {log.n_queries}")
+    qcfg = cfg["querylog"]
+    rng = np.random.default_rng([seed, 4])
+    top_pool = max(64, corpus.n_docs // 16)
+    terms = np.full((n, MAX_QUERY_TERMS), -1, np.int32)
+    n_terms = np.zeros(n, np.int32)
+    category = np.zeros(n, np.int8)
+    seed_doc = np.zeros(n, np.int32)
+    for i in range(n):
+        if rng.random() < float(qcfg["frac_cat2"]):
+            d = int(rng.integers(0, top_pool))
+            pool = np.union1d(corpus.field_terms[T][d], corpus.field_terms[U][d])
+            nt = int(rng.integers(2, 4))
+            category[i] = CAT2
+        else:
+            d = int(rng.integers(0, corpus.n_docs))
+            pool = np.intersect1d(corpus.field_terms[B][d],
+                                  corpus.topic_terms[corpus.doc_topic[d]])
+            if len(pool) < 2:
+                pool = corpus.field_terms[B][d]
+            nt = int(rng.integers(3, MAX_QUERY_TERMS + 1))
+            category[i] = CAT1
+        qt = rng.choice(pool, size=max(min(nt, len(pool)), 1), replace=False)
+        terms[i, :len(qt)] = qt
+        n_terms[i] = len(qt)
+        seed_doc[i] = d
+    n_judged = log.judged_ids.shape[1]
+    cat_all = np.concatenate([log.category, category])
+    ranks = np.empty(len(cat_all), np.int64)
+    ranks[np.argsort(cat_all)[::-1]] = np.arange(len(cat_all))
+    pop = (1.0 + ranks) ** -float(qcfg["zipf_a"])
+    return QueryLog(
+        terms=np.concatenate([log.terms, terms]),
+        n_terms=np.concatenate([log.n_terms, n_terms]),
+        popularity=pop / pop.sum(),
+        category=cat_all,
+        judged_ids=np.concatenate(
+            [log.judged_ids, np.full((n, n_judged), -1, np.int32)]),
+        judged_gains=np.concatenate(
+            [log.judged_gains, np.zeros((n, n_judged), np.int8)]),
+        seed_doc=np.concatenate([log.seed_doc, seed_doc]))
 
 
 def check_rules(ruleset, rules: dict) -> None:
