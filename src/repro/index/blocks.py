@@ -45,16 +45,19 @@ def pack_bits(bits: np.ndarray) -> np.ndarray:
     """Pack a boolean array (..., n_docs) into uint32 words (..., n_docs/32).
 
     Bit ``j`` of word ``w`` corresponds to doc ``w*32 + j`` (LSB-first).
-    Host-side (numpy) — used by the index builder.
+    Host-side (numpy) — used by the index builder.  Returns a
+    C-contiguous native-order array.
     """
     bits = np.asarray(bits, dtype=bool)
     n = bits.shape[-1]
     if n % WORD_BITS != 0:
         raise ValueError(f"trailing dim must be a multiple of {WORD_BITS}")
-    shaped = bits.reshape(*bits.shape[:-1], n // WORD_BITS, WORD_BITS)
-    weights = (1 << np.arange(WORD_BITS, dtype=np.uint64)).astype(np.uint64)
-    packed = (shaped.astype(np.uint64) * weights).sum(-1)
-    return packed.astype(np.uint32)
+    # Little bit order puts doc w*32 + j at bit j%8 of byte j//8;
+    # reading the four bytes little-endian makes that bit j of word w.
+    # packbits keeps a strided input's layout, so make the bytes
+    # contiguous (a no-op for the builder's C-ordered planes) to view.
+    packed = np.ascontiguousarray(np.packbits(bits, axis=-1, bitorder="little"))
+    return packed.view("<u4").astype(np.uint32, copy=False)
 
 
 def unpack_bits(words: np.ndarray) -> np.ndarray:

@@ -192,6 +192,69 @@ def test_pack_bits_empty_plane_is_zero_words():
     assert pack_bits(np.ones(32, bool))[0] == np.uint32(0xFFFFFFFF)
 
 
+def _reference_pack_bits(bits):
+    """The uint64 multiply-and-sum pack, kept verbatim as the oracle
+    for the ``np.packbits`` one."""
+    bits = np.asarray(bits, dtype=bool)
+    n = bits.shape[-1]
+    if n % 32 != 0:
+        raise ValueError("trailing dim must be a multiple of 32")
+    shaped = bits.reshape(*bits.shape[:-1], n // 32, 32)
+    weights = (1 << np.arange(32, dtype=np.uint64)).astype(np.uint64)
+    packed = (shaped.astype(np.uint64) * weights).sum(-1)
+    return packed.astype(np.uint32)
+
+
+def _assert_packs_like_reference(bits):
+    words = pack_bits(bits)
+    assert words.dtype == np.uint32
+    assert words.flags.c_contiguous
+    np.testing.assert_array_equal(words, _reference_pack_bits(bits))
+
+
+@pytest.mark.parametrize("density", [0.0, 0.01, 0.5, 1.0])
+@pytest.mark.parametrize("shape", [(96,), (3, 64), (4, 4, 4096)])
+def test_pack_bits_matches_reference(shape, density):
+    rng = np.random.default_rng(23)
+    _assert_packs_like_reference(rng.random(shape) < density)
+
+
+@pytest.mark.parametrize("make", [
+    pytest.param(lambda rng: (rng.random((128, 64)) < 0.5).T,
+                 id="transposed-view"),
+    pytest.param(lambda rng: (rng.random((2, 3, 256)) < 0.5).astype(np.uint8),
+                 id="uint8-0-1"),
+])
+def test_pack_bits_matches_reference_on_other_inputs(make):
+    bits = make(np.random.default_rng(24))
+    assert bits.dtype != bool or not bits.flags.c_contiguous
+    _assert_packs_like_reference(bits)
+
+
+def test_live_view_occupancy_words_unchanged_with_tombstones(monkeypatch):
+    """The live view's block-major words, base planes with tombstones
+    masked unioned with delta planes, are what the reference pack gives."""
+    from repro.index import builder
+    from repro.index.live import LiveIndex
+    from test_live_index import rand_doc, tiny_index
+
+    rng = np.random.default_rng(25)
+    live = LiveIndex(tiny_index(), capacity_docs=256)
+    live.add_documents([rand_doc(rng) for _ in range(5)])
+    for doc in (3, 40, 77):
+        live.update_document(doc, rand_doc(rng))
+    live.commit()
+    view = live.store.snapshot().view
+    assert view.delta.tombstones.size == 3
+    term_lists = ([1, 2, 3], [5, 17], list(range(MAX_QUERY_TERMS)))
+    got = [view.query_occupancy(ts) for ts in term_lists]
+    monkeypatch.setattr(builder, "pack_bits", _reference_pack_bits)
+    for ts, words in zip(term_lists, got):
+        want = view.query_occupancy(ts)
+        assert words.any() and words.flags.c_contiguous
+        np.testing.assert_array_equal(words, want)
+
+
 def test_occupancy_tail_block_zero_padded():
     """n_docs not a multiple of block_docs: the tail block's padding
     bits (docs beyond n_docs) must be zero in every plane."""
